@@ -73,6 +73,8 @@ def main() -> None:
     default = SMOKE_SUITES if args.smoke else SUITES
     only = tuple(args.only.split(",")) if args.only else default
 
+    from repro.launch.compile_cache import enable_compile_cache
+    print(f"# compile cache: {enable_compile_cache()}", flush=True)
     from . import (bench_frontier, bench_generate, bench_index,
                    bench_prefill, bench_replicas, bench_scheduler,
                    bench_speculative, fig2_precision_recall,

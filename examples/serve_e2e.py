@@ -103,8 +103,10 @@ def main():
 
     s, ss = eng.stats, sched.stats
     print(f"\n== serving report ==")
+    dev = jax.devices()[0]
     print(f"requests {ss.completed} in {dt:.1f}s "
-          f"({dt/max(ss.completed,1)*1e3:.0f} ms/req wall CPU)")
+          f"({dt/max(ss.completed,1)*1e3:.0f} ms/req wall on "
+          f"{dev.platform} {dev.device_kind})")
     print(f"scheduler: batches={ss.batches} mean_batch={ss.mean_batch:.1f} "
           f"dedup_joined={ss.joined}")
     print(f"routing: miss={s.miss} tweak={s.tweak} exact={s.exact} "

@@ -219,6 +219,9 @@ JIT_REGISTRY: Tuple[JitSite, ...] = (
                  "the cascade's second stage, DESIGN.md §13)"),
     JitSite("launch/train.py", "main",
             note="CLI training step; params/opt threaded functionally"),
+    JitSite("launch/serve.py", "build_stack",
+            note="one-time seeded init of a full-width model, compiled so "
+                 "weights are made on the device in their own dtype"),
     JitSite("launch/dryrun.py", "run_one", donate=None, static=None,
             note="train-step lowering probe; donation gated on --donate "
                  "to measure aliasing impact, shardings vary per arch"),

@@ -1,7 +1,8 @@
-"""qwen2.5-3b [dense] — GQA with QKV bias. [hf:Qwen/Qwen2.5-0.5B family]
+"""qwen2.5-3b [dense] — GQA with QKV bias. [hf:Qwen/Qwen2.5-3B config.json]
 
-36L d_model=2048 16H (GQA kv=2) d_ff=11008 vocab=151936.
-Full attention -> long_500k skipped.
+36L d_model=2048 16H (GQA kv=2, head_dim 128) d_ff=11008 vocab=151936,
+rope_theta 1e6, tied input/output embeddings (tie_word_embeddings: true),
+3.09B parameters.  Full attention -> long_500k skipped.
 """
 from repro.models.config import ModelConfig, ATTN
 
@@ -9,7 +10,7 @@ CONFIG = ModelConfig(
     name="qwen2.5-3b", family="dense",
     num_layers=36, d_model=2048, num_heads=16, num_kv_heads=2,
     d_ff=11008, vocab_size=151936, block_pattern=(ATTN,),
-    qkv_bias=True, mlp_type="swiglu", norm_type="rmsnorm",
+    qkv_bias=True, mlp_type="swiglu", norm_type="rmsnorm", tie_embeddings=True,
     rope_theta=1_000_000.0, max_seq_len=32768 + 8,
     dtype="bfloat16", remat=True, train_microbatches=4,
 )

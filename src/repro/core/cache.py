@@ -102,7 +102,22 @@ def _victim_slot(state, cfg: CacheConfig):
 
 
 def _normalize(emb):
-    return emb / jnp.maximum(jnp.linalg.norm(emb), 1e-8)
+    """Unit-normalize along the last axis.
+
+    The sum of squares is a fixed pairwise tree of elementwise adds, not
+    a reduce: XLA orders a reduce differently for a (D,) row than for a
+    (B, D) batch, so a row's stored embedding would depend on the batch
+    it was inserted with.  Elementwise adds have one order, so batched
+    and single-entry inserts write the same bits.
+    """
+    sq = emb * emb
+    d = sq.shape[-1]
+    width = 1 << (d - 1).bit_length()
+    sq = jnp.pad(sq, [(0, 0)] * (sq.ndim - 1) + [(0, width - d)])
+    while sq.shape[-1] > 1:
+        half = sq.shape[-1] // 2
+        sq = sq[..., :half] + sq[..., half:]
+    return emb / jnp.maximum(jnp.sqrt(sq), 1e-8)
 
 
 def _fifo_batch_plan(ptr, row, count, capacity: int):
@@ -164,7 +179,7 @@ def insert_batch(state, cfg: CacheConfig, embs, q_tokens, q_mask,
     b = embs.shape[0]
     # clamp so ptr/clock/size never advance past the rows actually written
     count = jnp.minimum(jnp.asarray(b if count is None else count, jnp.int32), b)
-    embs = jax.vmap(_normalize)(embs)
+    embs = _normalize(embs)
     row = jnp.arange(b, dtype=jnp.int32)
     active = row < count
 
@@ -254,6 +269,24 @@ def lookup(state, cfg: CacheConfig, q_embs):
                        impl=cfg.lookup_impl, block_n=min(cfg.block_n, cfg.capacity))
 
 
+def _stamp_hits(state, w):
+    """Hit accounting at rows ``w``: stamp ``last_used`` with the clock,
+    bump ``hits``, tick the clock.  Out-of-bounds rows are dropped.
+
+    Each scatter names its output sharding as its operand's own.  On a
+    row-sharded bank the operand is sharded and ``w`` replicated, and
+    JAX does not pick the output sharding of such a scatter by itself.
+    """
+    lu, hits = state["last_used"], state["hits"]
+    new = dict(state)
+    new["last_used"] = lu.at[w].set(state["clock"], mode="drop",
+                                    out_sharding=jax.typeof(lu).sharding)
+    new["hits"] = hits.at[w].add(1, mode="drop",
+                                 out_sharding=jax.typeof(hits).sharding)
+    new["clock"] = state["clock"] + 1
+    return new
+
+
 def touch(state, cfg: CacheConfig, indices):
     """Record cache hits for LRU/LFU accounting.  indices: (B,) top-1 hits.
 
@@ -264,11 +297,7 @@ def touch(state, cfg: CacheConfig, indices):
     """
     indices = jnp.asarray(indices)
     w = jnp.where(indices >= 0, indices, cfg.capacity)
-    new = dict(state)
-    new["last_used"] = state["last_used"].at[w].set(state["clock"], mode="drop")
-    new["hits"] = state["hits"].at[w].add(1, mode="drop")
-    new["clock"] = state["clock"] + 1
-    return new
+    return _stamp_hits(state, w)
 
 
 def lookup_and_touch(state, cfg: CacheConfig,
@@ -286,10 +315,7 @@ def lookup_and_touch(state, cfg: CacheConfig,
     top1 = idx[:, 0]
     hit = (decisions != router_lib.MISS) & (top1 >= 0)
     w = jnp.where(hit, top1, cfg.capacity)  # OOB -> dropped for misses
-    new = dict(state)
-    new["last_used"] = state["last_used"].at[w].set(state["clock"], mode="drop")
-    new["hits"] = state["hits"].at[w].add(1, mode="drop")
-    new["clock"] = state["clock"] + 1
+    new = _stamp_hits(state, w)
     return new, scores, idx, decisions
 
 
@@ -312,11 +338,7 @@ def route_touch_core(state, cfg: CacheConfig, router_cfg, q_embs, scores,
     hit = ((decisions == router_lib.TWEAK)
            | (decisions == router_lib.EXACT)) & (top1 >= 0)
     w = jnp.where(hit, top1, cfg.capacity)  # OOB -> dropped for misses
-    new = dict(state)
-    new["last_used"] = state["last_used"].at[w].set(state["clock"],
-                                                    mode="drop")
-    new["hits"] = state["hits"].at[w].add(1, mode="drop")
-    new["clock"] = state["clock"] + 1
+    new = _stamp_hits(state, w)
     b = scores.shape[0]
     if cfg.index == "ivf":
         # centroids are replicated in the sharded layout, so the cluster
@@ -393,11 +415,7 @@ def make_second_stage(cfg: CacheConfig, router_cfg, rr_params, rr_cfg,
         slot = jnp.where(unc & commit, chosen, idx[:, 0])
         touch = unc & commit & (slot >= 0)
         w = jnp.where(touch, slot, cfg.capacity)
-        new = dict(state)
-        new["last_used"] = state["last_used"].at[w].set(state["clock"],
-                                                        mode="drop")
-        new["hits"] = state["hits"].at[w].add(1, mode="drop")
-        new["clock"] = state["clock"] + 1
+        new = _stamp_hits(state, w)
         if cfg.index == "ivf":
             ema, cnt = router_lib.admission_update(
                 state["adm_ema"], state["adm_count"], cluster, commit, unc,

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.kernels.cosine_topk.ops import cosine_topk, cosine_topk_gather
 from . import cache as cache_lib
@@ -120,11 +119,11 @@ def _flat_shard_lookup(mesh: Mesh, cfg: cache_lib.CacheConfig, axis: str):
         gi = jnp.where(i >= 0, i + shard * local_c, -1)
         return _merge_shard_topk(s, gi, axis, n_shards, k)
 
-    return shard_map(
+    return jax.shard_map(
         local_lookup, mesh=mesh,
         in_specs=(P(axis), P(axis), P()),
         out_specs=(P(), P()),
-        check_rep=False)
+        check_vma=False)
 
 
 def _ivf_shard_lookup(mesh: Mesh, cfg: cache_lib.CacheConfig, axis: str):
@@ -147,11 +146,11 @@ def _ivf_shard_lookup(mesh: Mesh, cfg: cache_lib.CacheConfig, axis: str):
         top_s, top_i = _merge_shard_topk(s, gi, axis, n_shards, k)
         return top_s, jnp.where(jnp.isfinite(top_s), top_i, -1)
 
-    return shard_map(
+    return jax.shard_map(
         local_lookup, mesh=mesh,
         in_specs=(P(axis),) * 6 + (P(), P()),
         out_specs=(P(), P()),
-        check_rep=False)
+        check_vma=False)
 
 
 def _sharded_lookup_call(sm, state, q_embs, ivf: bool):
@@ -276,7 +275,7 @@ def make_distributed_insert_batch(mesh: Mesh, cfg: cache_lib.CacheConfig,
         mine = keep & (gslot // local_c == shard)
         lslot = (gslot % local_c).astype(jnp.int32)
         w = jnp.where(mine, lslot, local_c)            # OOB -> dropped
-        embs = jax.vmap(cache_lib._normalize)(embs)
+        embs = cache_lib._normalize(embs)
         upd = lambda buf, val: buf.at[w].set(val.astype(buf.dtype),
                                              mode="drop")
         out = (upd(emb_buf, embs), upd(qt_buf, qt), upd(qm_buf, qm),
@@ -311,11 +310,11 @@ def make_distributed_insert_batch(mesh: Mesh, cfg: cache_lib.CacheConfig,
     n_ivf = len(index_lib.IVF_KEYS) if ivf else 0
     # centroids + pending + overflow replicate; table + back-ptrs shard
     ivf_in = (P(), P(axis), P(axis), P(axis), P(axis), P(), P())[:n_ivf]
-    sm = shard_map(
+    sm = jax.shard_map(
         local_insert, mesh=mesh,
         in_specs=(P(axis),) * 8 + (P(),) * 3 + (P(),) * 6 + ivf_in,
         out_specs=(P(axis),) * 8 + (P(),) * 4 + ivf_in,
-        check_rep=False)
+        check_vma=False)
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def insert_batch(state, embs, q_tokens, q_mask, r_tokens, r_mask,

@@ -31,8 +31,11 @@ def _kernel(q_ref, db_ref, valid_ref, out_s_ref, out_i_ref,
 
     q = q_ref[...].astype(jnp.float32)              # (B, D)
     db = db_ref[...].astype(jnp.float32)            # (block_n, D)
+    # float32 passes on the MXU: a bf16 pass scores a row against itself
+    # ~1e-3 off 1.0, below the router's EXACT threshold
     scores = jax.lax.dot_general(
         q, db, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)          # (B, block_n)
     base = step * block_n
     col = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1) + base
@@ -53,20 +56,25 @@ def _topk_merge(rs, ri, s, idx, k: int):
 
     k rounds of masked max; the loser of each slot comparison is
     re-injected into the pool to compete for the next slot (VPU-friendly:
-    no gather, no sort network).
+    no gather, no sort network).  Slot j of the running top-k is written
+    through a column mask, and the winner is the lowest lane holding the
+    max (a min over a masked iota): Mosaic lowers neither a
+    dynamic_update_slice nor an argmax.
     """
+    lane = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, rs.shape, 1)
+    m = s.shape[1]
     for j in range(k):
         best = jnp.max(s, axis=1, keepdims=True)                    # (B,1)
-        bidx = jnp.argmax(s, axis=1)                                # (B,)
-        consumed = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) == bidx[:, None]
+        bidx = jnp.min(jnp.where(s == best, lane, m), axis=1, keepdims=True)
+        consumed = lane == bidx
         bcol = jnp.sum(jnp.where(consumed, idx, 0), axis=1, keepdims=True)
         slot_s = rs[:, j:j + 1]
         slot_i = ri[:, j:j + 1]
         take_new = best > slot_s
-        rs = jax.lax.dynamic_update_slice(
-            rs, jnp.where(take_new, best, slot_s), (0, j))
-        ri = jax.lax.dynamic_update_slice(
-            ri, jnp.where(take_new, bcol, slot_i), (0, j))
+        at_j = col == j
+        rs = jnp.where(at_j, jnp.where(take_new, best, slot_s), rs)
+        ri = jnp.where(at_j, jnp.where(take_new, bcol, slot_i), ri)
         s = jnp.where(consumed & take_new, jnp.broadcast_to(slot_s, s.shape), s)
         idx = jnp.where(consumed & take_new, jnp.broadcast_to(slot_i, idx.shape), idx)
     return rs, ri
@@ -83,10 +91,10 @@ def _gather_kernel(q_ref, cand_ref, idx_ref, valid_ref, out_s_ref, out_i_ref,
 
     q = q_ref[...].astype(jnp.float32)               # (B, D)
     cand = cand_ref[...].astype(jnp.float32)         # (B, block_m, D)
-    # per-query candidate sets: batched matvec on the MXU
-    scores = jax.lax.dot_general(
-        q, cand, (((1,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)           # (B, block_m)
+    # per-query candidate sets: one (1, D) x (block_m, D) matvec per
+    # query, as a VPU multiply and a lane reduce (Mosaic has no batched
+    # dot with a (B, D) operand)
+    scores = jnp.sum(q[:, None, :] * cand, axis=2)   # (B, block_m)
     scores = jnp.where(valid_ref[...] != 0, scores, NEG)
     idx = idx_ref[...]                                # (B, block_m)
 

@@ -22,7 +22,7 @@ NEG = -1e30
 
 
 def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-            block_t: int, batch: int):
+            block_t: int):
     t_step = pl.program_id(1)
 
     @pl.when(t_step == 0)
@@ -35,7 +35,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     k = k_ref[0].astype(jnp.float32)          # (block_t, dh)
     v = v_ref[0].astype(jnp.float32)
     dh = q.shape[-1]
-    cache_len = len_ref[0]
+    cache_len = len_ref[pl.program_id(0)]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * (dh ** -0.5)
     tp = t_step * block_t + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -74,7 +74,7 @@ def _kernel_block(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
     k = k_ref[0].astype(jnp.float32)          # (block_t, dh)
     v = v_ref[0].astype(jnp.float32)
     dh = q.shape[-1]
-    cache_len = len_ref[0]
+    cache_len = len_ref[pl.program_id(0)]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * (dh ** -0.5)
     tp = t_step * block_t + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -115,24 +115,28 @@ def decode_attention_block_pallas(q, k, v, cache_len, *, block_t: int = 1024,
         vt = jnp.pad(vt, ((0, 0), (0, pt), (0, 0)))
     nt = (t + pt) // block_t
     grid = (b * hk, nt)
-    lens = jnp.broadcast_to(cache_len[:, None], (b, hk)).reshape(b * hk)
+    lens = jnp.broadcast_to(cache_len[:, None], (b, hk)).reshape(
+        b * hk).astype(jnp.int32)
 
-    out = pl.pallas_call(
-        functools.partial(_kernel_block, block_t=block_t, g=g),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,          # per-(row, kv head) lengths, SMEM
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda bk, j: (bk,)),
-            pl.BlockSpec((1, kq * g, dh), lambda bk, j: (bk, 0, 0)),
-            pl.BlockSpec((1, block_t, dh), lambda bk, j: (bk, j, 0)),
-            pl.BlockSpec((1, block_t, dh), lambda bk, j: (bk, j, 0)),
+            pl.BlockSpec((1, kq * g, dh), lambda bk, j, lens: (bk, 0, 0)),
+            pl.BlockSpec((1, block_t, dh), lambda bk, j, lens: (bk, j, 0)),
+            pl.BlockSpec((1, block_t, dh), lambda bk, j, lens: (bk, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, kq * g, dh), lambda bk, j: (bk, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * hk, kq * g, dh), q.dtype),
+        out_specs=pl.BlockSpec((1, kq * g, dh), lambda bk, j, lens: (bk, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((kq * g, 1), jnp.float32),
             pltpu.VMEM((kq * g, 1), jnp.float32),
             pltpu.VMEM((kq * g, dh), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel_block, block_t=block_t, g=g),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b * hk, kq * g, dh), q.dtype),
         interpret=interpret,
     )(lens, qt, kt, vt)
     return jnp.moveaxis(out.reshape(b, hk, kq, g, dh), 1, 2).reshape(
@@ -155,24 +159,28 @@ def decode_attention_pallas(q, k, v, cache_len, *, block_t: int = 1024,
         vt = jnp.pad(vt, ((0, 0), (0, pt), (0, 0)))
     nt = (t + pt) // block_t
     grid = (b * hk, nt)
-    lens = jnp.broadcast_to(cache_len[:, None], (b, hk)).reshape(b * hk)
+    lens = jnp.broadcast_to(cache_len[:, None], (b, hk)).reshape(
+        b * hk).astype(jnp.int32)
 
-    out = pl.pallas_call(
-        functools.partial(_kernel, block_t=block_t, batch=b),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,          # per-(row, kv head) lengths, SMEM
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda bk, j: (bk,)),
-            pl.BlockSpec((1, g, dh), lambda bk, j: (bk, 0, 0)),
-            pl.BlockSpec((1, block_t, dh), lambda bk, j: (bk, j, 0)),
-            pl.BlockSpec((1, block_t, dh), lambda bk, j: (bk, j, 0)),
+            pl.BlockSpec((1, g, dh), lambda bk, j, lens: (bk, 0, 0)),
+            pl.BlockSpec((1, block_t, dh), lambda bk, j, lens: (bk, j, 0)),
+            pl.BlockSpec((1, block_t, dh), lambda bk, j, lens: (bk, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, g, dh), lambda bk, j: (bk, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * hk, g, dh), q.dtype),
+        out_specs=pl.BlockSpec((1, g, dh), lambda bk, j, lens: (bk, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, dh), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel, block_t=block_t),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b * hk, g, dh), q.dtype),
         interpret=interpret,
     )(lens, qt, kt, vt)
     return out.reshape(b, hk, g, dh).reshape(b, h, dh)

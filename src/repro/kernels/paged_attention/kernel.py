@@ -38,12 +38,13 @@ def _kernel(tbl_ref, q_ref, k_ref, v_ref, sp_ref, o_ref, m_scr, l_scr,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0].astype(jnp.float32)            # (g, dh)
-    k = k_ref[0, :, 0].astype(jnp.float32)      # (page, dh)
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    k = k_ref[0].astype(jnp.float32)            # (page, dh)
+    v = v_ref[0].astype(jnp.float32)
     dh = q.shape[-1]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * (dh ** -0.5)
-    s = jnp.where(sp_ref[0] >= 0, s, NEG)       # (g, page) vs (page,)
+    sp = sp_ref[0, pl.ds(j, 1), :]              # (1, page)
+    s = jnp.where(sp >= 0, s, NEG)              # (g, page)
 
     m_prev, l_prev, acc_prev = m_scr[...], l_scr[...], acc_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -76,14 +77,15 @@ def _kernel_block(tbl_ref, qpos_ref, q_ref, k_ref, v_ref, sp_ref, o_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0].astype(jnp.float32)            # (K*g, dh)
-    k = k_ref[0, :, 0].astype(jnp.float32)      # (page, dh)
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    k = k_ref[0].astype(jnp.float32)            # (page, dh)
+    v = v_ref[0].astype(jnp.float32)
     dh = q.shape[-1]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * (dh ** -0.5)
-    sp = sp_ref[0]                              # (1, page)
+    sp = sp_ref[0, pl.ds(j, 1), :]              # (1, page)
     row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // g
-    s = jnp.where((sp >= 0) & (sp <= qpos_ref[0] + row), s, NEG)
+    qpos = qpos_ref[pl.program_id(0)]
+    s = jnp.where((sp >= 0) & (sp <= qpos + row), s, NEG)
 
     m_prev, l_prev, acc_prev = m_scr[...], l_scr[...], acc_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -98,6 +100,17 @@ def _kernel_block(tbl_ref, qpos_ref, q_ref, k_ref, v_ref, sp_ref, o_ref,
     def _final():
         o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
                     ).astype(o_ref.dtype)
+
+
+def _head_lanes(pages):
+    """(P+1, page, Hk, dh) -> (P+1, page, Hk*dh), a free reshape.
+
+    A K/V block then selects one head as a lane-aligned (page, dh) tile;
+    a (page, 1, dh) block of the 4-D pool breaks the TPU tiling rule (the
+    second-minor block dim must divide by 8 or span the array).
+    """
+    n, page, hk, dh = pages.shape
+    return pages.reshape(n, page, hk * dh)
 
 
 def paged_decode_attention_block_pallas(q, kp, vp, block_tbl, slot_pos,
@@ -115,23 +128,26 @@ def paged_decode_attention_block_pallas(q, kp, vp, block_tbl, slot_pos,
     sp = jnp.pad(slot_pos, ((0, 0), (0, npg * page - cap)),
                  constant_values=-1).reshape(b, npg, page)
     tbl = block_tbl.astype(jnp.int32)
-    qpos = jnp.broadcast_to(q_pos[:, None], (b, hk)).reshape(b * hk)
+    qpos = jnp.broadcast_to(q_pos[:, None], (b, hk)).reshape(
+        b * hk).astype(jnp.int32)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,          # block table + query positions
         grid=(b * hk, npg),
         in_specs=[
-            pl.BlockSpec((1,), lambda bh, j, tbl: (bh,)),
-            pl.BlockSpec((1, kq * g, dh), lambda bh, j, tbl: (bh, 0, 0)),
-            pl.BlockSpec((1, page, 1, dh),
-                         lambda bh, j, tbl: (tbl[bh // hk, j], 0,
-                                             bh % hk, 0)),
-            pl.BlockSpec((1, page, 1, dh),
-                         lambda bh, j, tbl: (tbl[bh // hk, j], 0,
-                                             bh % hk, 0)),
-            pl.BlockSpec((1, 1, page), lambda bh, j, tbl: (bh // hk, j, 0)),
+            pl.BlockSpec((1, kq * g, dh),
+                         lambda bh, j, tbl, qpos: (bh, 0, 0)),
+            pl.BlockSpec((1, page, dh),
+                         lambda bh, j, tbl, qpos: (tbl[bh // hk, j], 0,
+                                                   bh % hk)),
+            pl.BlockSpec((1, page, dh),
+                         lambda bh, j, tbl, qpos: (tbl[bh // hk, j], 0,
+                                                   bh % hk)),
+            pl.BlockSpec((1, npg, page),
+                         lambda bh, j, tbl, qpos: (bh // hk, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, kq * g, dh), lambda bh, j, tbl: (bh, 0, 0)),
+        out_specs=pl.BlockSpec((1, kq * g, dh),
+                               lambda bh, j, tbl, qpos: (bh, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((kq * g, 1), jnp.float32),
             pltpu.VMEM((kq * g, 1), jnp.float32),
@@ -143,7 +159,7 @@ def paged_decode_attention_block_pallas(q, kp, vp, block_tbl, slot_pos,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hk, kq * g, dh), q.dtype),
         interpret=interpret,
-    )(tbl, qpos, qt, kp, vp, sp)
+    )(tbl, qpos, qt, _head_lanes(kp), _head_lanes(vp), sp)
     return jnp.moveaxis(out.reshape(b, hk, kq, g, dh), 1, 2).reshape(
         b, kq, h, dh)
 
@@ -169,15 +185,16 @@ def paged_decode_attention_pallas(q, kp, vp, block_tbl, slot_pos, *,
         grid=(b * hk, npg),
         in_specs=[
             pl.BlockSpec((1, g, dh), lambda bh, j, tbl: (bh, 0, 0)),
-            # K/V tile: physical page tbl[row, j] of this row's KV head —
-            # the block table indirection happens HERE, in the DMA source.
-            pl.BlockSpec((1, page, 1, dh),
-                         lambda bh, j, tbl: (tbl[bh // hk, j], 0,
-                                             bh % hk, 0)),
-            pl.BlockSpec((1, page, 1, dh),
-                         lambda bh, j, tbl: (tbl[bh // hk, j], 0,
-                                             bh % hk, 0)),
-            pl.BlockSpec((1, 1, page), lambda bh, j, tbl: (bh // hk, j, 0)),
+            # K/V tile: physical page tbl[row, j], lanes of this row's KV
+            # head — the block table indirection happens HERE, in the DMA
+            # source.
+            pl.BlockSpec((1, page, dh),
+                         lambda bh, j, tbl: (tbl[bh // hk, j], 0, bh % hk)),
+            pl.BlockSpec((1, page, dh),
+                         lambda bh, j, tbl: (tbl[bh // hk, j], 0, bh % hk)),
+            # the row's whole slot_pos, fetched once per row: a (1, page)
+            # block would break the (8, 128) tiling rule
+            pl.BlockSpec((1, npg, page), lambda bh, j, tbl: (bh // hk, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, g, dh), lambda bh, j, tbl: (bh, 0, 0)),
         scratch_shapes=[
@@ -191,5 +208,5 @@ def paged_decode_attention_pallas(q, kp, vp, block_tbl, slot_pos, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hk, g, dh), q.dtype),
         interpret=interpret,
-    )(tbl, qt, kp, vp, sp)
+    )(tbl, qt, _head_lanes(kp), _head_lanes(vp), sp)
     return out.reshape(b, hk, g, dh).reshape(b, h, dh)

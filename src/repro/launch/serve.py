@@ -7,17 +7,21 @@ reports the paper's §5.2.3 economics — hit-rate split, token volumes,
 cost vs all-Big baseline — plus the scheduler's coalescing stats.
 
   PYTHONPATH=src python -m repro.launch.serve --queries 200 --profile lmsys
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-3b --queries 48
 """
 from __future__ import annotations
 
 import argparse
 import time
+from typing import Optional
 
 import jax
 
+from repro.configs import get_config
 from repro.core import (CacheConfig, ReplicaGroup, RouterConfig,
                         TweakLLMEngine)
 from repro.data import WorkloadGenerator
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_cache_mesh
 from repro.models import ModelConfig, build_model
 from repro.models.embedder import tiny_embedder_config, init_embedder
@@ -29,8 +33,13 @@ from repro.tokenizer import HashWordTokenizer
 from repro.training.embedder_train import train_embedder
 from repro.training.reranker_train import train_reranker
 
+# The lmsys sample of this seed holds an exact resend (request 13 repeats
+# request 0, two batches later), so a 48-query trace fires EXACT as well.
+TRACE_SEED = 4
 
-def build_stack(*, vocab: int = 8192, capacity: int = 4096,
+
+def build_stack(*, arch: Optional[str] = None, vocab: int = 8192,
+                capacity: int = 4096,
                 train_embedder_steps: int = 60, policy: str = "fifo",
                 lookup_impl: str = "xla", index: str = "flat",
                 nclusters: int = 0, nprobe: int = 8, threshold: float = 0.7,
@@ -38,33 +47,57 @@ def build_stack(*, vocab: int = 8192, capacity: int = 4096,
                 admit_floor: float = 0.0, seed: int = 0):
     """Shared model stack + configs for one engine or a replica group.
 
+    ``arch`` names a config in ``repro.configs`` (``--arch``): the MISS
+    and the TWEAK generator then both serve that model at its published
+    widths and dtype, from ONE set of seeded weights (one copy on the
+    device), and the tokenizer, embedder and sampler take its
+    vocabulary (``vocab`` is ignored).  Without ``arch`` a toy float32
+    pair serves: a 4-layer d128 MISS model and a 2-layer d64 TWEAK
+    model, sized for CPU tests.
+
     ``band > 0`` turns on the router cascade (DESIGN.md §13): the stack
     then also builds + trains the cross-encoder reranker the second
     stage scores shortlists with, returned under the ``reranker`` key
     that ``TweakLLMEngine`` / ``ReplicaGroup.build`` accept.
     """
+    if arch is None:
+        big_cfg = ModelConfig(name="big", num_layers=4, d_model=128,
+                              num_heads=8, num_kv_heads=4, d_ff=256,
+                              vocab_size=vocab, max_seq_len=1024,
+                              dtype="float32")
+        # The small (tweak) model uses fixed-block flash attention so the
+        # engine's shared-prefix KV reuse applies on every TWEAK hit
+        # (DESIGN.md §9) — naive/auto softmax would disqualify it from the
+        # byte-identical prefix-prefill contract.
+        small_cfg = big_cfg.replace(name="small", num_layers=2, d_model=64,
+                                    num_heads=4, num_kv_heads=2, d_ff=128,
+                                    attention_impl="xla_flash",
+                                    flash_block_q=32, flash_block_k=32)
+        big_m, small_m = build_model(big_cfg), build_model(small_cfg)
+        big_params = big_m.init(jax.random.PRNGKey(1))  # seed: ok demo CLI, fixed init for reproducibility
+        small_params = small_m.init(jax.random.PRNGKey(2))  # seed: ok demo CLI, fixed init for reproducibility
+    else:
+        big_cfg = get_config(arch)
+        vocab = big_cfg.vocab_size
+        # TWEAK keeps the fixed-block flash attention prefix reuse needs.
+        # Queries tile 32 rows (suffix buckets start at 16); keys tile 128,
+        # one lane width, which covers the 45-token instruction prefix
+        # plus a typical suffix in one block.
+        small_cfg = big_cfg.replace(attention_impl="xla_flash",
+                                    flash_block_q=32, flash_block_k=128)
+        big_m, small_m = build_model(big_cfg), build_model(small_cfg)
+        big_params = small_params = jax.jit(big_m.init)(
+            jax.random.PRNGKey(1))  # seed: ok demo CLI, fixed init for reproducibility
     tok = HashWordTokenizer(vocab)
     ecfg = tiny_embedder_config(vocab)
     eparams = init_embedder(jax.random.PRNGKey(seed), ecfg)
     if train_embedder_steps:
         eparams, _ = train_embedder(eparams, ecfg, tok,
                                     steps=train_embedder_steps, batch=16)
-    big_cfg = ModelConfig(name="big", num_layers=4, d_model=128, num_heads=8,
-                          num_kv_heads=4, d_ff=256, vocab_size=vocab,
-                          max_seq_len=1024, dtype="float32")
-    # The small (tweak) model uses fixed-block flash attention so the
-    # engine's shared-prefix KV reuse applies on every TWEAK hit
-    # (DESIGN.md §9) — naive/auto softmax would disqualify it from the
-    # byte-identical prefix-prefill contract.
-    small_cfg = big_cfg.replace(name="small", num_layers=2, d_model=64,
-                                num_heads=4, num_kv_heads=2, d_ff=128,
-                                attention_impl="xla_flash",
-                                flash_block_q=32, flash_block_k=32)
-    big_m, small_m = build_model(big_cfg), build_model(small_cfg)
     gen_cfg = GenerateConfig(max_new_tokens=16,
                              sampler=SamplerConfig(vocab_size=vocab))
-    big = Generator(big_m, big_m.init(jax.random.PRNGKey(1)), gen_cfg)  # seed: ok demo CLI, fixed init for reproducibility
-    small = Generator(small_m, small_m.init(jax.random.PRNGKey(2)), gen_cfg)  # seed: ok demo CLI, fixed init for reproducibility
+    big = Generator(big_m, big_params, gen_cfg)
+    small = Generator(small_m, small_params, gen_cfg)
     cache_cfg = CacheConfig(capacity=capacity, dim=ecfg.d_model,
                             policy=policy, lookup_impl=lookup_impl,
                             index=index, nclusters=nclusters, nprobe=nprobe)
@@ -98,6 +131,10 @@ def build_replica_group(n: int, *, shared: bool = True,
 
 def main():
     ap = argparse.ArgumentParser()
+    ap.add_argument("--arch", default=None,
+                    help="serve this config from repro.configs at its "
+                         "published widths (e.g. qwen2.5-3b); default: a "
+                         "toy float32 pair")
     ap.add_argument("--queries", type=int, default=200)
     ap.add_argument("--batch", type=int, default=8,
                     help="scheduler max_batch (unique queries per dispatch)")
@@ -135,8 +172,13 @@ def main():
                          "baseline the replica bench compares against)")
     args = ap.parse_args()
 
+    cache_dir = enable_compile_cache()
+    dev = jax.devices()[0]
+    print(f"device: {dev.platform} {dev.device_kind} x{len(jax.devices())}, "
+          f"compile cache {cache_dir}")
     print("building TweakLLM stack (training embedder contrastively)...")
-    kw = dict(threshold=args.threshold, policy=args.policy, index=args.index,
+    kw = dict(arch=args.arch, threshold=args.threshold, policy=args.policy,
+              index=args.index,
               train_embedder_steps=args.embedder_steps, band=args.band,
               train_reranker_steps=args.reranker_steps,
               admit_floor=args.admit_floor)
@@ -153,9 +195,9 @@ def main():
         eng = build_engine(**kw)
         sched = Scheduler(eng, scfg, clock=SimClock())
         stats_src = eng
-    wl = WorkloadGenerator(profile=args.profile, seed=0)  # seed: ok demo CLI, reproducible trace
+    wl = WorkloadGenerator(profile=args.profile, seed=TRACE_SEED)
     texts = [q.text for q in wl.sample(args.queries)]
-    trace = poisson_trace(texts, args.rate, seed=0)  # seed: ok demo CLI, reproducible trace
+    trace = poisson_trace(texts, args.rate, seed=TRACE_SEED)
     t0 = time.time()
     done = replay_trace(sched, trace)
     dt = time.time() - t0
@@ -165,7 +207,8 @@ def main():
     s, ss = stats_src.stats, sched.stats
     print(f"\n== TweakLLM serving report ({args.profile} profile) ==")
     print(f"requests: {ss.completed}  ({dt/max(ss.completed,1)*1e3:.1f} "
-          f"ms/request wall on CPU)")
+          f"ms/request wall on {dev.platform} {dev.device_kind}, "
+          f"compiles included)")
     print(f"scheduler: batches={ss.batches} mean_batch={ss.mean_batch:.1f} "
           f"dedup_joined={ss.joined} rejected={ss.rejected}")
     if args.replicas > 1:

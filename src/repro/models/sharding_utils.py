@@ -1,38 +1,33 @@
-"""Mesh-aware sharding constraints that degrade to no-ops off-mesh.
+"""Mesh-aware sharding constraints for model code.
 
-``constrain(x, *axes)`` applies with_sharding_constraint only for axes that
-exist in the ambient (abstract) mesh AND divide the corresponding dim —
-so model code runs unchanged on a single CPU device (tests), on the host
-mesh (examples) and on the 512-device production mesh (dry-run).
+``constrain(x, *axes)`` applies with_sharding_constraint for the axes that
+exist in the ambient mesh AND divide the corresponding dim.  With no
+ambient mesh (one device: tests, one-chip serving) it returns ``x``
+unchanged; under a mesh (the dry run's ``jax.set_mesh``) a constraint
+that cannot be applied raises.
 
 The BATCH sentinel expands to ('pod','data') / 'data' as available.
 """
 from __future__ import annotations
 
+import math
+
 import jax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 BATCH = "__batch__"
 
 
 def _mesh_axes():
-    try:
-        am = jax.sharding.get_abstract_mesh()
-    except Exception:
-        return {}
-    if am is None:
-        return {}
-    try:
-        axes = dict(zip(am.axis_names, am.axis_sizes))
-        # Inside shard_map, manual axes must not appear in sharding
-        # constraints — keep only Auto axes.
-        types = getattr(am, "axis_types", None)
-        if types is not None:
-            axes = {n: s for (n, s), t in zip(axes.items(), types)
-                    if "auto" in str(t).lower()}
-        return axes
-    except Exception:
-        return {}
+    """Auto axes of the ambient mesh as ``{name: size}``; {} off-mesh.
+
+    Inside shard_map the manual axes must not appear in sharding
+    constraints, so only Auto axes count.
+    """
+    am = jax.sharding.get_abstract_mesh()
+    return {n: s for n, s, t in zip(am.axis_names, am.axis_sizes,
+                                    am.axis_types)
+            if t == AxisType.Auto}
 
 
 def constrain(x, *spec):
@@ -49,19 +44,8 @@ def constrain(x, *spec):
             resolved.append(None)
             continue
         names = a if isinstance(a, tuple) else (a,)
-        size = 1
-        ok = True
-        for n in names:
-            if n not in axes:
-                ok = False
-                break
-            size *= axes[n]
-        if not ok or dim % size != 0:
-            resolved.append(None)
-        else:
-            resolved.append(a)
+        fits = (all(n in axes for n in names)
+                and dim % math.prod(axes[n] for n in names) == 0)
+        resolved.append(a if fits else None)
     resolved += [None] * (x.ndim - len(resolved))
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*resolved))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, P(*resolved))

@@ -6,7 +6,6 @@ the bucket set, and an unrolled decode loop (DESIGN.md §10).
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.analysis import contracts
 from repro.analysis.contracts import (
@@ -61,7 +60,7 @@ def test_unexpected_donation_is_caught():
 
 
 def test_f64_promotion_is_caught():
-    with enable_x64():  # lowering must also happen inside the x64 scope
+    with jax.enable_x64(True):  # lowering must also happen inside the x64 scope
         tr = jax.jit(lambda x: x.astype(jnp.float64) * 2.0).trace(
             jnp.ones((4,), jnp.float32))
         failures = check_traced("widened", tr)

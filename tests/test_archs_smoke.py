@@ -95,3 +95,10 @@ def test_exact_config_specs():
     assert get_config("qwen3-moe-235b-a22b").num_experts == 128
     assert get_config("qwen3-moe-235b-a22b").experts_per_token == 8
     assert get_config("mamba2-130m").ssm_state == 128
+
+
+def test_qwen2_5_3b_param_count_matches_published():
+    """Qwen2.5-3B ties its embeddings; the published size is 3.09B."""
+    cfg = get_config("qwen2.5-3b")
+    assert cfg.tie_embeddings
+    assert abs(cfg.param_count() / 3.09e9 - 1.0) < 0.01

@@ -36,6 +36,9 @@ def run_device_script(body: str, *, n_dev: int = 8, timeout: int = 600):
     script = _PREAMBLE.format(n_dev=n_dev) + textwrap.dedent(body)
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    # forced host devices are CPU devices: the child must never reach for
+    # an accelerator, which a parent process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", script], env=env,
                          capture_output=True, text=True, timeout=timeout)
     assert out.returncode == 0, (

@@ -130,6 +130,43 @@ def test_cosine_topk_self_retrieval():
     np.testing.assert_allclose(np.asarray(s)[:, 0], 1.0, atol=1e-5)
 
 
+def _dot_precisions(jaxpr):
+    """Precision of every dot_general in a jaxpr, sub-jaxprs included
+    (a pallas_call's kernel body among them)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append(eqn.params["precision"])
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)      # ClosedJaxpr -> Jaxpr
+                if hasattr(sub, "eqns"):
+                    found += _dot_precisions(sub)
+    return found
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("gather", [False, True])
+def test_cosine_scores_run_at_float32_precision(impl, gather):
+    """Cache scores must not take a TPU's default single bf16 MXU pass:
+    that scores a verbatim resend ~1e-3 below 1.0, under the router's
+    0.9999 EXACT threshold.  The CPU computes float32 either way, so the
+    precision is checked in the program."""
+    q, db = jnp.ones((8, 64)), jnp.ones((256, 64))
+    if gather:
+        fn = lambda q, db: cosine_topk_gather(
+            q, db, jnp.zeros((8, 128), jnp.int32), jnp.ones((8, 128), bool),
+            k=4, impl=impl, block_m=128)
+    else:
+        fn = lambda q, db: cosine_topk(q, db, None, k=4, impl=impl,
+                                       block_n=128)
+    precisions = _dot_precisions(jax.make_jaxpr(fn)(q, db).jaxpr)
+    # the Pallas gather kernel scores on the VPU in float32: no dot at all
+    assert precisions or (impl, gather) == ("pallas", True)
+    hi = jax.lax.Precision.HIGHEST
+    assert all(p is not None and tuple(p) == (hi, hi) for p in precisions)
+
+
 # --------------------------------------------------------- flash attention
 
 @pytest.mark.parametrize("b,sq,sk,h,hk,dh,bq,bk,causal,win", [
