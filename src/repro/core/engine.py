@@ -39,7 +39,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models.embedder import encode as embed_encode
+from repro.models import embedder as embedder_lib
+from repro.serving import trace
 from repro.serving.batcher import (bucket_batch, bucket_len, floor_len_bucket,
                                    pad_to_buckets)
 from repro.serving.generate import Generator
@@ -202,11 +203,14 @@ class SharedCacheBank:
         if mesh is None:
             self.state = state
             # fused lookup + calibrated route + hit-accounting; cache state
-            # donated so the touch happens in place (DESIGN.md §5)
-            self._lookup_touch = jax.jit(
-                lambda s, q, c: cache_lib.lookup_route_touch(
-                    s, cache_cfg, router_cfg, q, c),
-                donate_argnums=(0,))
+            # donated so the touch happens in place (DESIGN.md §5).  The
+            # function's name names the program in profiler traces.
+            def lookup_route_touch(s, q, c):
+                return cache_lib.lookup_route_touch(s, cache_cfg, router_cfg,
+                                                    q, c)
+
+            self._lookup_touch = jax.jit(lookup_route_touch,
+                                         donate_argnums=(0,))
             self._insert = cache_lib.make_insert_batch(cache_cfg)
         else:
             from . import distributed as dist_lib
@@ -370,8 +374,10 @@ class TweakLLMEngine:
         # (the seed replayed PRNGKey(0) for every batch)
         self._seed_seq = itertools.count()
 
-        self._embed = jax.jit(
-            lambda p, t, m: embed_encode(p, t, m, embedder_cfg))
+        def embed_encode(p, t, m):
+            return embedder_lib.encode(p, t, m, embedder_cfg)
+
+        self._embed = jax.jit(embed_encode)
 
     # cache state + text mirror live on the bank (shared across replicas);
     # these aliases keep the single-engine API unchanged
@@ -389,20 +395,24 @@ class TweakLLMEngine:
 
     # ------------------------------------------------------------- embed
     def embed_texts(self, texts: List[str]) -> jnp.ndarray:
-        return self._embed_with_lengths(texts)[0]
+        return self._embed_tokens(self._tokenize(texts)[0])
 
-    def _embed_with_lengths(self, texts: List[str]):
-        """(embeddings (n, D), real query-token lengths, query tokens/mask).
+    def _tokenize(self, texts: List[str]):
+        """Host half of the embed: ``((tokens, mask, n), real query-token
+        lengths, host tokens, host mask)``, the first padded to their
+        bucket and put on the device.
 
         Lengths come from the host-side tokenizer mask, not the device;
-        the (n, max_query_len) token arrays stay host-side — the stage-2
-        cascade device_puts them only when uncertain rows exist."""
+        the (n, max_query_len) host token arrays are what the stage-2
+        cascade device_puts, only when uncertain rows exist."""
         toks, mask = self.tok.encode_batch(texts, self.max_query_len)
         qlens = mask.sum(axis=1).astype(np.int64).tolist()
         ptoks, pmask, b = pad_to_buckets(toks, mask)
-        embs = self._embed(self.embedder_params, jnp.asarray(ptoks),
-                           jnp.asarray(pmask))[:b]
-        return embs, qlens, toks, mask
+        return (jnp.asarray(ptoks), jnp.asarray(pmask), b), qlens, toks, mask
+
+    def _embed_tokens(self, dev):
+        ptoks, pmask, b = dev
+        return self._embed(self.embedder_params, ptoks, pmask)[:b]
 
     # ------------------------------------------------------------- serve
     def handle_batch(self, queries: List[str], *, max_new_tokens: int = 32,
@@ -435,7 +445,11 @@ class TweakLLMEngine:
         calibrated routing curve (scalar, per-row list with None = config
         default, or None for all-default).
         """
-        queries = [tweak_lib.preprocess_query(q) for q in queries]
+        with trace.span("engine.dispatch", rows=len(queries)):
+            return self._handle_batch(queries, max_new_tokens,
+                                      cost_thresholds)
+
+    def _handle_batch(self, queries, max_new_tokens, cost_thresholds):
         n = len(queries)
         if n == 0:
             return BatchResult([], [])
@@ -443,13 +457,17 @@ class TweakLLMEngine:
         # mutation (lookup touches recency on device; EXACT rows bill
         # stats) so a ValueError cannot leave half-served accounting
         self._tweak_encode_len(max_new_tokens)
-        cost_l = self._resolve_costs(n, cost_thresholds)
-        embs, qlens, qtoks, qmask = self._embed_with_lengths(queries)
+        with trace.span("engine.tokenize"):
+            queries = [tweak_lib.preprocess_query(q) for q in queries]
+            cost_l = self._resolve_costs(n, cost_thresholds)
+            dev, qlens, qtoks, qmask = self._tokenize(queries)
+            cost_dev = (self.bank.default_cost(n) if cost_thresholds is None
+                        else jax.device_put(np.asarray(cost_l, np.float32)))  # hostsync: ok host list H2D, explicit put
         self.stats.baseline_prompt_tokens += sum(qlens)
-        cost_dev = (self.bank.default_cost(n) if cost_thresholds is None
-                    else jax.device_put(np.asarray(cost_l, np.float32)))  # hostsync: ok host list H2D, explicit put
-        d_scores, d_idx, d_dec, d_tau, d_cluster, d_admit = \
-            self.bank.route_batch(embs, cost_dev)
+        with trace.span("engine.route"):
+            embs = self._embed_tokens(dev)
+            d_scores, d_idx, d_dec, d_tau, d_cluster, d_admit = \
+                self.bank.route_batch(embs, cost_dev)
         # THE per-serve-batch device->host sync (DESIGN.md §5): scores,
         # slots, routing decisions, and admission flags pulled in one
         # device_get; the top-1 column is sliced on host (device-side
@@ -457,18 +475,20 @@ class TweakLLMEngine:
         # everything below works on host scalars.  The stage-2 resolve
         # below adds a SECOND sync, but only on batches that actually
         # carry uncertain rows — the certain path stays O(1).
-        scores, idxs, decisions, admit = jax.device_get(  # hostsync: ok the one per-batch sync
-            (d_scores, d_idx, d_dec, d_admit))
+        with trace.span("engine.route_sync"):
+            scores, idxs, decisions, admit = jax.device_get(  # hostsync: ok the one per-batch sync
+                (d_scores, d_idx, d_dec, d_admit))
         top1 = scores[:, 0]
         slot_arr = idxs[:, 0]
         stage2_rows = decisions == router_lib.UNCERTAIN
         n_unc = int(stage2_rows.sum())  # hostsync: ok numpy after the batch sync
         if n_unc:
-            final, slot, _conf = self.bank.second_stage(
-                jax.device_put(qtoks), jax.device_put(qmask),
-                d_scores, d_idx, d_dec, d_tau, d_cluster)
-            decisions, slot_arr = jax.device_get(  # hostsync: ok stage-2 resolve, fires only when uncertain rows exist
-                (final, slot))
+            with trace.span("engine.stage2", rows=n_unc):
+                final, slot, _conf = self.bank.second_stage(
+                    jax.device_put(qtoks), jax.device_put(qmask),
+                    d_scores, d_idx, d_dec, d_tau, d_cluster)
+                decisions, slot_arr = jax.device_get(  # hostsync: ok stage-2 resolve, fires only when uncertain rows exist
+                    (final, slot))
             self.stats.uncertain += n_unc
             self.stats.recovered += int(  # hostsync: ok numpy after the stage-2 sync
                 (decisions[stage2_rows] == router_lib.TWEAK).sum())
@@ -481,23 +501,27 @@ class TweakLLMEngine:
         prompt_tokens = [0] * n
 
         # EXACT: verbatim cached response
-        for i in np.nonzero(decisions == router_lib.EXACT)[0]:
-            slot = slot_l[i]
-            cached = self._text_store.get(slot)
-            responses[i] = cached[1] if cached else self._decode_cached(slot)
-            self.stats.exact += 1
+        with trace.span("engine.exact"):
+            for i in np.nonzero(decisions == router_lib.EXACT)[0]:
+                slot = slot_l[i]
+                cached = self._text_store.get(slot)
+                responses[i] = (cached[1] if cached
+                                else self._decode_cached(slot))
+                self.stats.exact += 1
         # TWEAK: small LLM refines cached response
         tweak_ids = np.nonzero(decisions == router_lib.TWEAK)[0]
         if len(tweak_ids):
-            self._run_tweak(queries, tweak_ids, slot_l, responses,
-                            max_new_tokens, gen_tokens, prompt_tokens)
+            with trace.span("engine.tweak", rows=len(tweak_ids)):
+                self._run_tweak(queries, tweak_ids, slot_l, responses,
+                                max_new_tokens, gen_tokens, prompt_tokens)
         # MISS: big LLM generates from scratch + cache insert (suppressed
         # for rows whose query cluster the admission EMA has shut)
         miss_ids = np.nonzero(decisions == router_lib.MISS)[0]
         if len(miss_ids):
-            self._run_miss(queries, miss_ids, embs, responses,
-                           max_new_tokens, gen_tokens, prompt_tokens,
-                           admit)
+            with trace.span("engine.miss", rows=len(miss_ids)):
+                self._run_miss(queries, miss_ids, embs, responses,
+                               max_new_tokens, gen_tokens, prompt_tokens,
+                               admit)
 
         self.stats.total += n
         # band_of mirrored on host with the ACTIVE config's edges: top1 is
@@ -897,11 +921,12 @@ class TweakLLMEngine:
         kept_ids = np.asarray([ids[j] for j in keep])  # hostsync: ok host list of slot ids
         # explicit device_put of the row indices: a host-array gather
         # would move them implicitly (transfer-guard unsafe)
-        self._insert_entries([texts[j] for j in keep],
-                             [resp_tokens[j] for j in keep],
-                             [resp_texts[j] for j in keep],
-                             jnp.take(embs, jax.device_put(kept_ids),
-                                      axis=0))
+        with trace.span("engine.insert", rows=len(keep)):
+            self._insert_entries([texts[j] for j in keep],
+                                 [resp_tokens[j] for j in keep],
+                                 [resp_texts[j] for j in keep],
+                                 jnp.take(embs, jax.device_put(kept_ids),
+                                          axis=0))
 
     # ------------------------------------------------- offline population
     def populate(self, queries: List[str], responses: List[str]):

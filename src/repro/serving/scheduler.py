@@ -42,6 +42,7 @@ import itertools
 import time
 from typing import Callable, Dict, Iterable, List, Optional, Protocol, Tuple
 
+from . import trace
 from .batcher import bucket_batch
 
 
@@ -163,12 +164,6 @@ class SchedulerStats:
     big_tokens: int = 0
     small_tokens: int = 0
     busy_time: float = 0.0        # modeled engine-busy simulated seconds
-    latency_sum: float = 0.0
-    latency_max: float = 0.0
-
-    @property
-    def mean_latency(self) -> float:
-        return self.latency_sum / max(self.completed, 1)
 
     @property
     def mean_batch(self) -> float:
@@ -281,13 +276,15 @@ class Scheduler:
             return
         take = min(len(self._groups), self.cfg.max_batch)
         groups = self._groups[:take]
-        result = self._serve(groups)
-        start = max(self.clock.now(), self._busy_until)
-        service = self.service_model(take) if self.service_model else 0.0
-        finish = start + service
-        self._busy_until = finish
-        self.stats.busy_time += service
-        self._complete(groups, result, finish)
+        with _dispatch_span(groups) as sp:
+            dispatched = self.clock.now()
+            result = self._serve(groups)
+            start = max(self.clock.now(), self._busy_until)
+            service = self.service_model(take) if self.service_model else 0.0
+            finish = start + service
+            self._busy_until = finish
+            self.stats.busy_time += service
+            _complete(self, groups, result, dispatched, finish, sp.dispatch)
 
     def _dispatch_continuous(self) -> None:
         """Slot-based dispatch: the cohort is whatever fits the slots that
@@ -297,16 +294,18 @@ class Scheduler:
         free = [i for i, t in enumerate(self._slot_free) if t <= start]
         take = min(len(self._groups), len(free), self.cfg.max_batch)
         groups = self._groups[:take]
-        result = self._serve(groups)
-        # each request holds one slot for its steady-state share of a
-        # full-slot fused decode: finishing frees ONLY that slot
-        service = (self.service_model(self.cfg.slots) / self.cfg.slots
-                   if self.service_model else 0.0)
-        finish = start + service
-        for i in free[:take]:
-            self._slot_free[i] = finish
-        self.stats.busy_time += service * take
-        self._complete(groups, result, finish)
+        with _dispatch_span(groups) as sp:
+            dispatched = self.clock.now()
+            result = self._serve(groups)
+            # each request holds one slot for its steady-state share of a
+            # full-slot fused decode: finishing frees ONLY that slot
+            service = (self.service_model(self.cfg.slots) / self.cfg.slots
+                       if self.service_model else 0.0)
+            finish = start + service
+            for i in free[:take]:
+                self._slot_free[i] = finish
+            self.stats.busy_time += service * take
+            _complete(self, groups, result, dispatched, finish, sp.dispatch)
 
     def _serve(self, groups):
         # engine first, queue mutation after: if the engine raises, every
@@ -325,25 +324,6 @@ class Scheduler:
                 self._by_text.pop((g[0].text, g[0].cost_threshold), None)
         return result
 
-    def _complete(self, groups, result, finish: float) -> None:
-        self.stats.batches += 1
-        self.stats.dispatched += len(groups)
-        self.stats.big_tokens += result.big_tokens
-        self.stats.small_tokens += result.small_tokens
-        for group, resp, meta in zip(groups, result.responses, result.meta):
-            for j, req in enumerate(group):
-                req.response = resp
-                req.meta = dict(meta)
-                req.joined = j > 0
-                req.finish = finish
-                self.stats.completed += 1
-                self.stats.joined += int(j > 0)
-                lat = finish - req.arrival
-                self.stats.latency_sum += lat
-                self.stats.latency_max = max(self.stats.latency_max, lat)
-                self._completed.append(req)
-        self._n_pending -= sum(len(g) for g in groups)
-
 
 # ------------------------------------------------------------ replicas
 @dataclasses.dataclass
@@ -357,6 +337,7 @@ class _Lane:
     worth of persistent decode slots).
     """
     engine: object
+    index: int = 0
     groups: List[List[Request]] = dataclasses.field(default_factory=list)
     busy_until: float = 0.0
     slot_free: List[float] = dataclasses.field(default_factory=list)
@@ -398,8 +379,9 @@ class ReplicaScheduler:
         self.clock = clock if clock is not None else WallClock()
         self.service_model = service_model
         self.stats = SchedulerStats()
-        self.lanes = [_Lane(engine=e, slot_free=[0.0] * self.cfg.slots)
-                      for e in engines]
+        self.lanes = [_Lane(engine=e, index=i,
+                            slot_free=[0.0] * self.cfg.slots)
+                      for i, e in enumerate(engines)]
         self._by_text: Dict[Tuple[str, Optional[float]],
                             List[Request]] = {}
         self._completed: List[Request] = []
@@ -526,26 +508,29 @@ class ReplicaScheduler:
             start = max(self.clock.now(), min(lane.slot_free))
             free = [i for i, t in enumerate(lane.slot_free) if t <= start]
             take = min(len(lane.groups), len(free), self.cfg.max_batch)
-            groups = lane.groups[:take]
-            result = self._serve(lane, groups)
-            service = (self.service_model(self.cfg.slots) / self.cfg.slots
-                       if self.service_model else 0.0)
-            finish = start + service
-            for i in free[:take]:
-                lane.slot_free[i] = finish
-            self.stats.busy_time += service * take
         else:
             take = min(len(lane.groups), self.cfg.max_batch)
-            groups = lane.groups[:take]
+        groups = lane.groups[:take]
+        with _dispatch_span(groups, lane=lane.index) as sp:
+            dispatched = self.clock.now()
             result = self._serve(lane, groups)
-            start = max(self.clock.now(), lane.busy_until)
-            service = self.service_model(take) if self.service_model else 0.0
-            finish = start + service
-            lane.busy_until = finish
-            self.stats.busy_time += service
-        lane.dispatched += len(groups)
-        lane.batches += 1
-        self._complete(groups, result, finish)
+            if self.cfg.continuous:
+                service = (self.service_model(self.cfg.slots) / self.cfg.slots
+                           if self.service_model else 0.0)
+                finish = start + service
+                for i in free[:take]:
+                    lane.slot_free[i] = finish
+                self.stats.busy_time += service * take
+            else:
+                start = max(self.clock.now(), lane.busy_until)
+                service = (self.service_model(take) if self.service_model
+                           else 0.0)
+                finish = start + service
+                lane.busy_until = finish
+                self.stats.busy_time += service
+            lane.dispatched += len(groups)
+            lane.batches += 1
+            _complete(self, groups, result, dispatched, finish, sp.dispatch)
 
     def _serve(self, lane: _Lane, groups):
         # engine first, queue mutation after — same crash discipline as
@@ -562,24 +547,41 @@ class ReplicaScheduler:
                 self._by_text.pop((g[0].text, g[0].cost_threshold), None)
         return result
 
-    def _complete(self, groups, result, finish: float) -> None:
-        self.stats.batches += 1
-        self.stats.dispatched += len(groups)
-        self.stats.big_tokens += result.big_tokens
-        self.stats.small_tokens += result.small_tokens
+
+# ----------------------------------------------------------- completion
+def _dispatch_span(groups, **attrs):
+    """The ``scheduler.dispatch`` span of one dispatch: ``rows`` unique
+    queries, ``requests`` with the copies that joined them."""
+    return trace.span("scheduler.dispatch", dispatch=trace.next_dispatch(),
+                      rows=len(groups), requests=sum(map(len, groups)),
+                      **attrs)
+
+
+def _complete(sched, groups, result, dispatched: float, finish: float,
+              dispatch) -> None:
+    """Hand a dispatch's results to its requests and count them; shared
+    by both schedulers.  ``dispatched`` and ``finish`` are on the
+    scheduler's clock."""
+    with trace.span("scheduler.complete"):
+        stats = sched.stats
+        stats.batches += 1
+        stats.dispatched += len(groups)
+        stats.big_tokens += result.big_tokens
+        stats.small_tokens += result.small_tokens
+        tracing = trace.active()
         for group, resp, meta in zip(groups, result.responses, result.meta):
             for j, req in enumerate(group):
                 req.response = resp
                 req.meta = dict(meta)
                 req.joined = j > 0
                 req.finish = finish
-                self.stats.completed += 1
-                self.stats.joined += int(j > 0)
-                lat = finish - req.arrival
-                self.stats.latency_sum += lat
-                self.stats.latency_max = max(self.stats.latency_max, lat)
-                self._completed.append(req)
-        self._n_pending -= sum(len(g) for g in groups)
+                stats.completed += 1
+                stats.joined += int(j > 0)
+                if tracing:
+                    trace.request(req.rid, req.arrival, dispatched, finish,
+                                  dispatch)
+                sched._completed.append(req)
+        sched._n_pending -= sum(len(g) for g in groups)
 
 
 # ------------------------------------------------------------- replay
