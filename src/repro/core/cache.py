@@ -6,9 +6,10 @@ insertion policy.  The paper ships append-only (== ring/FIFO here, which is
 append-only until capacity); LRU and LFU are implemented as the
 §6.2 "cache eviction policies" extension.
 
-Lookup dispatches to the Pallas ``cosine_topk`` kernel (TPU target) or its
-XLA reference; ``repro.core.distributed`` wraps it in shard_map for the
-sharded production cache.
+Lookup dispatches to the Pallas ``cosine_topk`` kernel or its XLA path
+(the reference's scores, selected by an exact chunked top-k);
+``repro.core.distributed`` wraps it in shard_map for the sharded
+production cache.
 
 Write path (DESIGN.md §5): ``insert`` is the one-entry reference;
 ``insert_batch`` commits a whole miss batch in a single jitted step (fixed
@@ -267,6 +268,14 @@ def lookup(state, cfg: CacheConfig, q_embs):
     ``cfg.index`` picks the scan: "flat" brute-forces the whole bank,
     "ivf" probes the top-``nprobe`` clusters of the member table
     (DESIGN.md §7; identical results at ``nprobe == nclusters``).
+
+    The flat scan with ``lookup_impl="xla"`` scores every row at float32
+    ``HIGHEST`` precision and selects the top-k exactly, ties included,
+    in two stages: the best score of each chunk of ``chunk_width(N)``
+    rows (``sqrt(N)`` rounded to a power of two, at least 128; 1,024 for
+    a 1,048,576-row bank), then one top-k over the k best chunks.  A bank
+    under 16,384 rows, or of fewer than 4k chunks, takes one plain top-k
+    (``kernels/cosine_topk/ops.py``).
     """
     if cfg.index == "ivf":
         return index_lib.lookup(state, cfg, q_embs)

@@ -1,12 +1,16 @@
 """Pallas kernel validation: shape/dtype sweeps + hypothesis properties,
 always against the pure-jnp ref.py oracles (interpret mode on CPU)."""
+import itertools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from _hypothesis_shim import given, settings, st
 
-from repro.kernels.cosine_topk.ops import cosine_topk, cosine_topk_gather
+from repro.kernels.cosine_topk.ops import (CHUNKED_MIN_N, chunk_width,
+                                           chunked_top_k, cosine_topk,
+                                           cosine_topk_gather)
 from repro.kernels.cosine_topk.ref import (cosine_topk_gather_ref,
                                            cosine_topk_ref)
 from repro.kernels.decode_attention.ops import (decode_attention,
@@ -59,6 +63,51 @@ def test_cosine_topk_property(b, logn, d, k, seed):
     s1 = np.asarray(s1)
     assert np.all(np.diff(s1, axis=1) <= 1e-6)
     assert np.all((np.asarray(i1) >= 0) & (np.asarray(i1) < n))
+
+
+def _same_bits(a, b):
+    a, b = np.asarray(a), np.asarray(b)
+    return a.dtype == b.dtype and np.array_equal(a.view(np.uint8),
+                                                 b.view(np.uint8))
+
+
+@pytest.mark.parametrize("n", [1000, CHUNKED_MIN_N, 20_000])
+@pytest.mark.parametrize("case", ["distinct", "dupes", "some_invalid",
+                                  "all_invalid"])
+@pytest.mark.parametrize("b,k", [(1, 1), (32, 2), (1, 3), (32, 4)])
+def test_cosine_topk_xla_is_exact(n, case, b, k):
+    """The XLA lookup's chunked selection equals one full lax.top_k bit
+    for bit, scores and indices, ties and -inf rows included; at 1,000
+    rows the lookup takes the plain top_k, so the helper is also run at
+    chunk widths that do (8) and do not (24) divide the bank, on the
+    scores and on the scores rounded to quarters (ties at every rank)."""
+    d = 64
+    key = jax.random.PRNGKey(n + 7 * k + b)
+    db = _unit(key, (n, d))
+    valid = jnp.ones((n,), bool)
+    if case == "dupes":       # every row repeated ~10x across chunks
+        rows = jax.random.randint(jax.random.fold_in(key, 1), (n,), 0, n // 10)
+        db = db[rows]
+    elif case == "some_invalid":
+        valid = jax.random.bernoulli(jax.random.fold_in(key, 2), 0.3, (n,))
+    elif case == "all_invalid":
+        valid = jnp.zeros((n,), bool)
+    # half the queries are resends of bank rows, so the top scores tie
+    picks = jax.random.randint(jax.random.fold_in(key, 3), (b,), 0, n)
+    q = jnp.where((jnp.arange(b) % 2 == 0)[:, None], db[picks],
+                  _unit(jax.random.fold_in(key, 4), (b, d)))
+    s1, i1 = cosine_topk(q, db, valid, k=k, impl="xla")
+    s2, i2 = jax.jit(cosine_topk_ref, static_argnums=2)(q, db, k, valid)
+    assert _same_bits(s1, s2) and _same_bits(i1, i2)
+    if n >= CHUNKED_MIN_N:
+        assert n // chunk_width(n) >= 4 * k      # the chunked path ran
+    scores = jnp.where(valid[None, :], q @ db.T, -jnp.inf)
+    coarse = jnp.round(scores * 4) / 4      # ties below each row's best
+    for panel, chunk in itertools.product((scores, coarse), (8, 24)):
+        want_s, want_i = jax.lax.top_k(panel, k)
+        got_s, got_i = chunked_top_k(panel, k, chunk)
+        assert _same_bits(got_s, want_s)
+        assert _same_bits(got_i, want_i.astype(got_i.dtype))
 
 
 @pytest.mark.parametrize("b,n,m,d,k,bm", [
