@@ -31,7 +31,6 @@ import ml_dtypes
 import numpy as np
 
 from bench import harness
-from bench.reference import dense as ref_lm
 from bench.reference import route as ref_route
 
 SAMPLE_ROWS = 8
@@ -90,10 +89,12 @@ def sample_rows(rows: List[dict], seed: int, k: int = SAMPLE_ROWS):
     return [rows[i] for i in pick] + [rows[rest[int(j)]] for j in extra]
 
 
-def logit_gaps(weights, rows, model: dict, control: bool = False) -> Dict:
-    m = {k: model[k] for k in ("num_attention_heads", "num_key_value_heads",
-                               "rms_norm_eps", "rope_theta", "vocab_size",
-                               "tie_word_embeddings")}
+def logit_gaps(weights, rows, model: dict, family: str,
+               control: bool = False) -> Dict:
+    """``gen_logit_gap`` of the sampled rows, by the reference module of
+    the model's ``family``."""
+    ref_lm = harness.family_module(family)
+    m = {k: model[k] for k in ref_lm.KEYS}
     seqs = [(r["prompt"], r["served"]) for r in rows]
     targets = None
     if control:

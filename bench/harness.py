@@ -12,6 +12,10 @@ process or metric is a file the harness finds by the name
 - ``arrivals/<kind>.py``: ``drive(window)``, the arrival process;
 - ``metrics/<metric>.py``: ``read(run)``, one metric from the run's
   records, or None where the run holds nothing to read;
+- ``reference/<family>.py``: what the benchmark knows of a model
+  family's language model: its plain reference and control, the fields
+  the program's configuration must hold, its weight rules (the module's
+  docstring of ``reference/dense.py`` lists what one exports);
 - ``counts/<family>.py``: operations and bytes from a model's shapes;
 - ``peaks.json``: the chip's peaks by ``device_kind``.
 """
@@ -51,6 +55,18 @@ def load_module(kind: str, name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def family_module(family: str):
+    """The reference module of a model family, ``reference/<family>.py``."""
+    try:
+        return load_module("reference", family)
+    except FileNotFoundError:
+        raise FileNotFoundError(
+            f"model family {family!r} has no reference module: the benchmark "
+            f"needs bench/reference/{family}.py (KEYS, served_gaps, "
+            f"program_fields, FAN_IN) to build, check and draw weights for "
+            f"it") from None
 
 
 @dataclasses.dataclass
@@ -340,12 +356,13 @@ def build(cell: Cell, seed: int):
     from bench import weights as weights_lib
 
     cfg = cell.config
+    family = family_module(cfg["family"])
     stack = build_stack(**cfg["build_stack"])
     big, small = stack["big"], stack["small"]
-    check_config(cfg, big.model.cfg, stack["router_cfg"])
+    check_config(cfg, big.model.cfg, stack["router_cfg"], family)
     big.params = small.params = None
     gc.collect()
-    w = weights_lib.make(big.model.init, seed)
+    w = weights_lib.make(big.model.init, seed, rules=family.FAN_IN)
     big.params = small.params = w
     ecfg = stack["embedder_cfg"]
     stack["embedder_params"] = weights_lib.make(
@@ -359,17 +376,10 @@ def build(cell: Cell, seed: int):
     return engine, stack, w
 
 
-def check_config(cfg: dict, mc, rc) -> None:
-    """The program must serve what the configuration file states."""
-    m = cfg["model"]
-    want = {"d_model": m["hidden_size"], "num_layers": m["num_hidden_layers"],
-            "num_heads": m["num_attention_heads"],
-            "num_kv_heads": m["num_key_value_heads"],
-            "d_ff": m["intermediate_size"], "vocab_size": m["vocab_size"],
-            "rope_theta": m["rope_theta"],
-            "tie_embeddings": m["tie_word_embeddings"],
-            "dtype": m["torch_dtype"],
-            "resolved_head_dim": m["hidden_size"] // m["num_attention_heads"]}
+def check_config(cfg: dict, mc, rc, family) -> None:
+    """The program must serve what the configuration file states: the
+    model's fields as the ``family`` module reads them, and the router's."""
+    want = family.program_fields(cfg["model"])
     got = {k: getattr(mc, k) for k in want}
     bad = {k: (got[k], v) for k, v in want.items() if got[k] != v}
     r = cfg["router"]
@@ -464,6 +474,9 @@ class Run:
     trace_offset: float = 0.0
     trace_start: Optional[float] = None
     t_close: Optional[float] = None
+    # the program's spans and request records (repro.serving.trace), in a
+    # traced run
+    program_records: Optional[object] = None
 
     @property
     def open_loop(self) -> bool:

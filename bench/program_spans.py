@@ -11,7 +11,6 @@ up to ``run.host_end``, the profiler's start in a traced run.
 """
 from __future__ import annotations
 
-import bisect
 import collections
 from typing import Dict, List, Optional
 
@@ -86,16 +85,20 @@ def idle_by_span(events, plane: str, lo: float,
     the innermost host span (program or ``bench.*``) covering each
     instant; ``outside any span`` where none does."""
     spans = sorted(host_spans(events), key=lambda e: e.start_ns)
-    starts = [e.start_ns for e in spans]
     out: Dict[str, float] = collections.defaultdict(float)
+    # the gaps come in order and do not overlap: sweep the spans once,
+    # keeping those begun before the gap's end and not ended by its start
+    live, nxt = [], 0
     for gs, ge in tracing.gaps(events, plane, lo, hi):
-        cands = [e for e in spans[:bisect.bisect_left(starts, ge)]
-                 if e.end_ns > gs]
-        cuts = sorted({gs, ge} | {t for e in cands
+        while nxt < len(spans) and spans[nxt].start_ns < ge:
+            live.append(spans[nxt])
+            nxt += 1
+        live = [e for e in live if e.end_ns > gs]
+        cuts = sorted({gs, ge} | {t for e in live
                                   for t in (e.start_ns, e.end_ns)
                                   if gs < t < ge})
         for a, b in zip(cuts, cuts[1:]):
-            cover = [e for e in cands if e.start_ns <= a and e.end_ns >= b]
+            cover = [e for e in live if e.start_ns <= a and e.end_ns >= b]
             name = min(cover, key=lambda e: e.dur_ns).name if cover \
                 else OUTSIDE
             out[name] += (b - a) / 1e9

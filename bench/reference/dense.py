@@ -1,4 +1,22 @@
-"""Plain float32 reference of a dense decoder stack, and its fp8 control.
+"""What the benchmark knows of the ``dense`` family's language model: its
+plain float32 reference, its fp8 control, the fields the program's
+configuration must hold, and how its weights are drawn.
+
+The harness finds this module by a configuration's ``family``
+(``reference/<family>.py``).  A module for another family exports the
+same four names:
+
+- ``KEYS``: the keys of the configuration's ``model`` section that its
+  forward reads (``check.logit_gaps`` passes just these);
+- ``served_gaps(params, seqs, m, precision="f32", targets=None)``: per
+  (prompt, served) sequence, the gap by which each served token's logit
+  lies below the reference's best, and the reference's argmax there;
+  ``precision="fp8"`` is the control one step below the stated dtype;
+- ``program_fields(m)``: ``ModelConfig`` attribute -> the value the
+  program must serve, from the ``model`` section (``harness.check_config``);
+- ``FAN_IN``: leaf name -> the axes (counted from the end) whose product
+  is the fan-in, for the leaves the family adds beyond the table shared
+  in ``bench/weights.py``; empty here.
 
 A straight-line forward of the stack the ``dense`` configurations state
 (RMSNorm, grouped-query attention with QKV bias, rotate-half rotary
@@ -24,6 +42,25 @@ import jax.numpy as jnp
 import numpy as np
 
 HIGHEST = jax.lax.Precision.HIGHEST
+
+KEYS = ("num_attention_heads", "num_key_value_heads", "rms_norm_eps",
+        "rope_theta", "vocab_size", "tie_word_embeddings")
+# every leaf of the dense stack is in the shared table
+FAN_IN = {}
+
+
+def program_fields(m) -> dict:
+    """``ModelConfig`` attribute -> the value the configuration states."""
+    return {"d_model": m["hidden_size"], "num_layers": m["num_hidden_layers"],
+            "num_heads": m["num_attention_heads"],
+            "num_kv_heads": m["num_key_value_heads"],
+            "d_ff": m["intermediate_size"], "vocab_size": m["vocab_size"],
+            "rope_theta": m["rope_theta"],
+            "tie_embeddings": m["tie_word_embeddings"],
+            "dtype": m["torch_dtype"],
+            "resolved_head_dim": (m.get("head_dim")
+                                  or m["hidden_size"]
+                                  // m["num_attention_heads"])}
 
 
 def _q(x, precision):

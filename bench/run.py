@@ -7,7 +7,9 @@ weights and traffic drawn from ``--seed``; prefills the bank, warms every
 program the window runs, then drives the scheduler on the wall clock for
 ``--seconds`` and drains it.  ``--trace 0`` reports the cell's end-to-end
 metrics, ``--trace 1`` its per-layer metrics from spans, counters and a
-profiler window inside the measured one.  After the window the
+profiler window inside the measured one; the program's own tracer
+(``repro.serving.trace``) records its spans through the window of a
+traced run, and stays off in an untraced one.  After the window the
 deployment's state is freed and what it served is checked against the
 plain references (``check.py``).
 
@@ -61,7 +63,7 @@ def main(argv=None, *, cell=None, require_tpu: bool = True,
     args = parse(argv)
     import jax
 
-    from bench import check, harness, tracing
+    from bench import check, harness, program_spans, tracing
     from bench.harness import log, now
 
     if cell is None:
@@ -81,6 +83,7 @@ def main(argv=None, *, cell=None, require_tpu: bool = True,
 
     from repro.launch.compile_cache import enable_compile_cache
     from repro.serving import Scheduler, SchedulerConfig, WallClock
+    from repro.serving import trace as program_trace
     log(f"device {devices[0].platform} {kind} x{len(devices)}, "
         f"compile cache {enable_compile_cache()}")
     # every program cached, however quick to compile or small
@@ -121,7 +124,16 @@ def main(argv=None, *, cell=None, require_tpu: bool = True,
     w = harness.Window(sched, live, cell.traffic, args.seconds, args.seed,
                        rec, tracer)
     rec.phase = "window"
-    harness.load_module("arrivals", cell.traffic["arrivals"]).drive(w)
+    drive = harness.load_module("arrivals", cell.traffic["arrivals"]).drive
+    program_records = None
+    if args.trace:
+        program_trace.start()
+        try:
+            drive(w)
+        finally:
+            program_records = program_trace.stop()
+    else:
+        drive(w)
     rec.phase = "after"
     t_drained = now()
     stats = {k: v - stats0[k] for k, v in vars(engine.stats).items()
@@ -140,7 +152,8 @@ def main(argv=None, *, cell=None, require_tpu: bool = True,
             r.tokens = int(r.meta["gen_tokens"])
     run = harness.Run(cell=cell, seed=args.seed, seconds=args.seconds,
                       reqs=w.reqs, t0=w.t0, rec=rec, setup_s=setup_s,
-                      peak=peak, kind=kind, t_close=w.t_close)
+                      peak=peak, kind=kind, t_close=w.t_close,
+                      program_records=program_records)
     device = {"platform": devices[0].platform, "kind": kind,
               "count": len(devices), "memory_peak_bytes": int(peak)}
     breakdown = None
@@ -156,6 +169,9 @@ def main(argv=None, *, cell=None, require_tpu: bool = True,
             device.update(busy_s=red["busy_s"], window_s=red["window_s"])
             breakdown = {"device_ops": red["device_ops"],
                          "idle_gaps": red["idle_gaps"]}
+            idle = program_spans.idle_by_span(events, red["planes"][0],
+                                              red["lo"], red["hi"])
+            log(f"idle by span: {idle}")
     metrics = harness.read_metrics(
         run, cell.per_layer if args.trace else cell.end_to_end)
 
@@ -195,7 +211,8 @@ def main(argv=None, *, cell=None, require_tpu: bool = True,
     numbers["route_mismatch"] = info.pop("route_mismatch")
     rows = check.sample_rows(check.generated_rows(gens), args.seed)
     if rows:
-        info.update(check.logit_gaps(weights, rows, model, control=control))
+        info.update(check.logit_gaps(weights, rows, model,
+                                     cell.config["family"], control=control))
         numbers["gen_logit_gap"] = info.pop("gen_logit_gap")
     limits = {k: v for k, v in cell.config["limits"].items()
               if k in numbers}

@@ -42,8 +42,8 @@ def test_served_gap_is_zero_for_reference_tokens_and_flags_an_altered_one():
             (_, arg), = ref_lm.served_gaps(w, [(p + served, [0])], m)
             served.append(int(arg[0]))
         rows.append({"kind": "miss", "prompt": p, "served": served})
-    assert check.logit_gaps(w, rows, tiny_cell.TINY_MODEL)[
+    assert check.logit_gaps(w, rows, tiny_cell.TINY_MODEL, "dense")[
         "gen_logit_gap"] < 1e-5
     rows[1]["served"][3] = (rows[1]["served"][3] + 1) % 512
-    assert check.logit_gaps(w, rows, tiny_cell.TINY_MODEL)[
+    assert check.logit_gaps(w, rows, tiny_cell.TINY_MODEL, "dense")[
         "gen_logit_gap"] > 0.01

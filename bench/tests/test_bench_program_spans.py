@@ -1,6 +1,6 @@
 """The readers of the program's own spans and of the lookup program's
-device time: on a traced tiny-cell run with the program's tracer on for
-the window, and on synthetic records and traces."""
+device time: on a traced tiny-cell run, in which ``bench/run.py`` turns the
+program's tracer on for the window, and on synthetic records and traces."""
 import json
 import types
 
@@ -17,53 +17,62 @@ HOST = "/host:CPU"
 MS = 1e6
 
 
-def _program_tracer_on(monkeypatch):
-    """Turn the program's tracer on for the window (from its start to the
-    end of the drain) and hand its records to the run's readers."""
-    from repro.serving import trace
+def _tiny_run(monkeypatch, capsys, trace: int):
+    """A tiny-cell run; its result line and the ``Run`` its metrics read."""
+    from bench import run
     got = {}
-    begin, drain = harness.Window.begin, harness.Window.drain
     read_metrics = harness.read_metrics
 
-    def begin_(self):
-        trace.start()
-        return begin(self)
+    def read_metrics_(r, names):
+        got["run"] = r
+        return read_metrics(r, names)
 
-    def drain_(self):
-        drain(self)
-        got["records"] = trace.stop()
-
-    def read_metrics_(run, names):
-        run.program_records = got.get("records")
-        run.cell.units.update(PROGRAM_METRICS)
-        return read_metrics(run, list(names) + list(PROGRAM_METRICS))
-
-    monkeypatch.setattr(harness.Window, "begin", begin_)
-    monkeypatch.setattr(harness.Window, "drain", drain_)
     monkeypatch.setattr(harness, "read_metrics", read_metrics_)
-    return got
+    tiny_cell.patch_program(monkeypatch)
+    rc = run.main(["--workload", tiny_cell.STAND_IN, "--seed",
+                   str(tiny_cell.SEED), "--seconds", "2", "--trace",
+                   str(trace)], cell=tiny_cell.cell(), require_tpu=False)
+    out = capsys.readouterr()
+    assert rc == 0, out.err[-3000:]
+    return json.loads(out.out.strip().splitlines()[-1]), got["run"]
 
 
 def test_traced_tiny_run_reports_the_program_span_metrics(monkeypatch,
                                                           capsys):
-    from bench import run
-    got = _program_tracer_on(monkeypatch)
-    tiny_cell.patch_program(monkeypatch)
-    rc = run.main(["--workload", tiny_cell.STAND_IN, "--seed",
-                   str(tiny_cell.SEED), "--seconds", "2", "--trace", "1"],
-                  cell=tiny_cell.cell(), require_tpu=False)
-    out = capsys.readouterr()
-    assert rc == 0, out.err[-3000:]
-    res = json.loads(out.out.strip().splitlines()[-1])
+    res, run = _tiny_run(monkeypatch, capsys, trace=1)
     m = {k: v["value"] for k, v in res["metrics"].items()}
     assert res["correct"] is True
     assert set(PROGRAM_METRICS) <= set(m)
+    assert {k: res["metrics"][k]["unit"] for k in PROGRAM_METRICS} == \
+        PROGRAM_METRICS
     assert "lookup_device_ms" not in m          # no device trace off the chip
     assert m["sched_batch_fill"] == pytest.approx(m["batch_fill"], abs=1e-12)
     assert m["engine_route_ms"] <= m["route_ms"]
     assert 0 < m["engine_host_ms"] <= m["engine_route_ms"]
     assert m["sched_queue_wait_p90_ms"] > 0
-    assert got["records"].spans and got["records"].requests
+    assert run.program_records.spans and run.program_records.requests
+
+
+def test_untraced_run_starts_no_program_tracer(monkeypatch, capsys):
+    from repro.serving import trace
+    starts = []
+    start = trace.start
+    monkeypatch.setattr(trace, "start",
+                        lambda: starts.append(1) or start())
+    res, run = _tiny_run(monkeypatch, capsys, trace=0)
+    assert res["correct"] is True
+    assert starts == [] and not trace.active()
+    assert run.program_records is None
+    assert not set(PROGRAM_METRICS) & set(res["metrics"])
+
+
+def test_program_span_metrics_are_in_the_benchmark():
+    spec = harness.load_json(harness.ROOT, "BENCHMARK.json")
+    per_layer = {m["name"]: m for m in spec["per_layer"]}
+    for name, unit in PROGRAM_METRICS.items():
+        assert per_layer[name]["source"] == "program_span"
+        assert per_layer[name]["unit"] == unit
+        assert per_layer[name]["workloads"] == [tiny_cell.STAND_IN]
 
 
 def _span(i, name, s, e, parent=None, dispatch=0, **attrs):

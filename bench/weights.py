@@ -6,9 +6,11 @@ the same arrays and nothing the program computed.  The layout (tree,
 shapes, dtypes) is read from the program's own ``init`` by
 ``jax.eval_shape``, without running it; each leaf is then drawn from the
 run's seed by its name: norm scales 1, norm biases 0, QKV biases
-N(0, 0.1), the token embedding N(0, 0.02), and every matrix N(0, 1/fan_in).
-A leaf of another name is an error: the reference would not know how to
-read it.
+N(0, 0.1), the token embedding N(0, 0.02), and every matrix N(0, 1/fan_in)
+by the fan-in table below, which the language model, the embedder and the
+reranker share, plus the rules a model family's reference module adds
+(its ``FAN_IN``).  A leaf of another name is an error: the reference
+would not know how to read it.
 """
 from __future__ import annotations
 
@@ -35,10 +37,11 @@ def _leaf_name(path) -> str:
     return str(getattr(last, "key", getattr(last, "name", last)))
 
 
-def make(init_fn, seed: int, stream: str = "weights"):
+def make(init_fn, seed: int, stream: str = "weights", rules=None):
     """Weights shaped as ``init_fn(key)`` would make them, drawn from one
     named stream of ``seed``; ``init_fn`` is only traced for its output
-    shapes."""
+    shapes.  ``rules`` adds fan-in rules, in the form of ``_FAN_IN``."""
+    fan_in = {**_FAN_IN, **(rules or {})}
     shapes = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
     leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
     plan = []
@@ -52,8 +55,8 @@ def make(init_fn, seed: int, stream: str = "weights"):
             plan.append(("normal", 0.1))
         elif name == "embed":
             plan.append(("normal", 0.02))
-        elif name in _FAN_IN:
-            fan = int(np.prod([sd.shape[a] for a in _FAN_IN[name]]))
+        elif name in fan_in:
+            fan = int(np.prod([sd.shape[a] for a in fan_in[name]]))
             plan.append(("normal", fan ** -0.5))
         else:
             raise ValueError(f"no rule to draw weight leaf {name!r} "
